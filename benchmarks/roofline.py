@@ -151,7 +151,6 @@ def run_collectives(quick: bool = True, density: float = 1 / 64):
     import jax
     import jax.numpy as jnp
     import numpy as np
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
 
     from benchmarks.common import timeit
@@ -207,8 +206,8 @@ def run_collectives(quick: bool = True, density: float = 1 / 64):
             "compacted": (body_compact, 2 * (k - 1) * F * 4),
         }
         for name, (body, wire) in progs.items():
-            fn = jax.jit(shard_map(body, mesh=mesh, in_specs=(P("x"),),
-                                   out_specs=P("x"), check_rep=False))
+            fn = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(P("x"),),
+                                       out_specs=P("x"), check_vma=False))
             t = timeit(fn, X, warmup=1, iters=3 if quick else 5)
             rows.append(dict(devices=k, strategy=name, time_s=t,
                              wire_bytes=wire))

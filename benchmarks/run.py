@@ -37,6 +37,8 @@ from . import (amsf_bench, dynamic_bench, execution_bench, gather_edges,
                sampling_quality, scale_bench, scan_bench, serve_bench,
                static_connectivity, streaming_batchsize,
                streaming_throughput, synthetic_families, tune_bench)
+# .common (imported above) puts src/ on sys.path
+from repro.launch.compile_cache import enable_compile_cache
 
 SUITES = {
     "static_connectivity": static_connectivity.run,     # Table 3
@@ -122,6 +124,7 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
     if args.full and args.quick:
         ap.error("--full and --quick are mutually exclusive")
+    enable_compile_cache()
     t0 = time.time()
     if args.apps:
         if args.only or args.exec_spec or args.serve:

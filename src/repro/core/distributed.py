@@ -51,7 +51,6 @@ from typing import Callable, NamedTuple, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..graphs.containers import round_up
@@ -144,8 +143,8 @@ def make_replicated_finish(mesh: Mesh, axes: Sequence[str],
     espec = P(axes)
     cap = _fixpoint_cap(mesh, axes, max_rounds)
 
-    @partial(shard_map, mesh=mesh, in_specs=(P(), espec, espec),
-             out_specs=(P(), P()), check_rep=False)
+    @partial(jax.shard_map, mesh=mesh, in_specs=(P(), espec, espec),
+             out_specs=(P(), P()), check_vma=False)
     def program(labels, s, r):
         if symmetrize:
             s, r = (jnp.concatenate([s, r]), jnp.concatenate([r, s]))
@@ -252,8 +251,8 @@ def make_sharded_finish(mesh: Mesh, edge_axes: Sequence[str], label_axis: str,
             return min(frontier, n1)
         return _auto_frontier(n1, ngather)
 
-    @partial(shard_map, mesh=mesh, in_specs=(lspec, espec, espec),
-             out_specs=(lspec, P()), check_rep=False)
+    @partial(jax.shard_map, mesh=mesh, in_specs=(lspec, espec, espec),
+             out_specs=(lspec, P()), check_vma=False)
     def program(lab_shard, s, r):
         if symmetrize:
             s, r = (jnp.concatenate([s, r]), jnp.concatenate([r, s]))
@@ -286,8 +285,8 @@ def make_sharded_finish(mesh: Mesh, edge_axes: Sequence[str], label_axis: str,
 
         return _outer_loop_flagged(body, lab_shard, rounds, cap)
 
-    @partial(shard_map, mesh=mesh, in_specs=(lspec, espec, espec),
-             out_specs=(lspec, P()), check_rep=False)
+    @partial(jax.shard_map, mesh=mesh, in_specs=(lspec, espec, espec),
+             out_specs=(lspec, P()), check_vma=False)
     def program_overlap(lab_shard, s, r):
         shard_len = lab_shard.shape[0]
         F = resolve_cap(shard_len)
@@ -371,8 +370,8 @@ def make_sharded_compress(mesh: Mesh, label_axis: str,
     """Full pointer-jump compression of a label-sharded array (one gather)."""
     lspec = P(label_axis)
 
-    @partial(shard_map, mesh=mesh, in_specs=(lspec,), out_specs=lspec,
-             check_rep=False)
+    @partial(jax.shard_map, mesh=mesh, in_specs=(lspec,), out_specs=lspec,
+             check_vma=False)
     def compress(lab_shard):
         shard_len = lab_shard.shape[0]
         idx = jax.lax.axis_index(label_axis)
@@ -523,9 +522,9 @@ def make_replicated_amsf(mesh: Mesh, axes: Sequence[str], *,
     espec = P(axes)
     cap = _fixpoint_cap(mesh, axes, max_rounds)
 
-    @partial(shard_map, mesh=mesh,
+    @partial(jax.shard_map, mesh=mesh,
              in_specs=(P(), P(), P(), espec, espec, espec),
-             out_specs=(P(), P(), P(), P(), P()), check_rep=False)
+             out_specs=(P(), P(), P(), P(), P()), check_vma=False)
     def program(labels, fu, fv, s, r, bids):
         gid = _shard_gid(mesh, axes, s.shape[0])
         return _bucket_sweep(labels, fu, fv, s, r, bids, gid, axes,
@@ -548,9 +547,9 @@ def make_sharded_amsf(mesh: Mesh, edge_axes: Sequence[str], label_axis: str,
     lspec = P(label_axis)
     cap = _fixpoint_cap(mesh, edge_axes, max_rounds)
 
-    @partial(shard_map, mesh=mesh,
+    @partial(jax.shard_map, mesh=mesh,
              in_specs=(lspec, P(), P(), espec, espec, espec),
-             out_specs=(lspec, P(), P(), P(), P()), check_rep=False)
+             out_specs=(lspec, P(), P(), P(), P()), check_vma=False)
     def program(lab_shard, fu, fv, s, r, bids):
         shard_len = lab_shard.shape[0]
         labels = jax.lax.all_gather(lab_shard, label_axis, tiled=True)
@@ -589,8 +588,8 @@ def make_replicated_stream(mesh: Mesh, axes: Sequence[str],
     run = make_replicated_finish(mesh, axes, finish_fn, rounds=rounds,
                                  max_rounds=max_rounds, symmetrize=True)
 
-    @partial(shard_map, mesh=mesh, in_specs=(P(), espec, espec),
-             out_specs=espec, check_rep=False)
+    @partial(jax.shard_map, mesh=mesh, in_specs=(P(), espec, espec),
+             out_specs=espec, check_vma=False)
     def query(labels, qa, qb):
         return labels[qa] == labels[qb]
 
@@ -624,8 +623,8 @@ def make_sharded_stream(mesh: Mesh, edge_axes: Sequence[str], label_axis: str,
                               kernels=kernels)
     compress = make_sharded_compress(mesh, label_axis, kernels=kernels)
 
-    @partial(shard_map, mesh=mesh, in_specs=(lspec, espec, espec),
-             out_specs=espec, check_rep=False)
+    @partial(jax.shard_map, mesh=mesh, in_specs=(lspec, espec, espec),
+             out_specs=espec, check_vma=False)
     def query(lab_shard, qa, qb):
         full = jax.lax.all_gather(lab_shard, label_axis, tiled=True)
         return full[qa] == full[qb]
@@ -738,22 +737,22 @@ def make_replicated_dynamic(mesh: Mesh, axes: Sequence[str], n: int, *,
     espec = P(axes)
     cap = _fixpoint_cap(mesh, axes, max_rounds)
 
-    @partial(shard_map, mesh=mesh,
+    @partial(jax.shard_map, mesh=mesh,
              in_specs=(P(), P(), P(), espec, espec, P(), P(), espec, espec),
-             out_specs=(P(), P(), P(), espec, espec, P()), check_rep=False)
+             out_specs=(P(), P(), P(), espec, espec, P()), check_vma=False)
     def update(labels, fu, fv, log_u, log_v, du, dv, bu, bv):
         return _dynamic_body(labels, fu, fv, log_u, log_v, du, dv, bu, bv,
                              n=n, mesh=mesh, axes=axes, compress=compress,
                              search_rounds=search_rounds, kernels=kernels,
                              cap=cap)
 
-    @partial(shard_map, mesh=mesh, in_specs=(P(), espec, espec),
-             out_specs=espec, check_rep=False)
+    @partial(jax.shard_map, mesh=mesh, in_specs=(P(), espec, espec),
+             out_specs=espec, check_vma=False)
     def query(labels, qa, qb):
         return labels[qa] == labels[qb]
 
-    @partial(shard_map, mesh=mesh, in_specs=(espec,), out_specs=espec,
-             check_rep=False)
+    @partial(jax.shard_map, mesh=mesh, in_specs=(espec,), out_specs=espec,
+             check_vma=False)
     def used(log_u):
         return jnp.sum(log_u < n, dtype=jnp.int32)[None]
 
@@ -777,10 +776,10 @@ def make_sharded_dynamic(mesh: Mesh, edge_axes: Sequence[str],
     lspec = P(label_axis)
     cap = _fixpoint_cap(mesh, edge_axes, max_rounds)
 
-    @partial(shard_map, mesh=mesh,
+    @partial(jax.shard_map, mesh=mesh,
              in_specs=(lspec, P(), P(), espec, espec, P(), P(), espec,
                        espec),
-             out_specs=(lspec, P(), P(), espec, espec, P()), check_rep=False)
+             out_specs=(lspec, P(), P(), espec, espec, P()), check_vma=False)
     def update(lab_shard, fu, fv, log_u, log_v, du, dv, bu, bv):
         shard_len = lab_shard.shape[0]
         full = jax.lax.all_gather(lab_shard, label_axis, tiled=True)
@@ -797,14 +796,14 @@ def make_sharded_dynamic(mesh: Mesh, edge_axes: Sequence[str],
                                              shard_len)
         return shard, fu, fv, log_u, log_v, rounds
 
-    @partial(shard_map, mesh=mesh, in_specs=(lspec, espec, espec),
-             out_specs=espec, check_rep=False)
+    @partial(jax.shard_map, mesh=mesh, in_specs=(lspec, espec, espec),
+             out_specs=espec, check_vma=False)
     def query(lab_shard, qa, qb):
         full = jax.lax.all_gather(lab_shard, label_axis, tiled=True)
         return full[qa] == full[qb]
 
-    @partial(shard_map, mesh=mesh, in_specs=(espec,), out_specs=espec,
-             check_rep=False)
+    @partial(jax.shard_map, mesh=mesh, in_specs=(espec,), out_specs=espec,
+             check_vma=False)
     def used(log_u):
         return jnp.sum(log_u < n, dtype=jnp.int32)[None]
 
@@ -844,8 +843,8 @@ def make_replicated_step(mesh: Mesh, axes: Sequence[str], *, jumps: int = 2,
     axes = tuple(axes)
     espec = P(axes)
 
-    @partial(shard_map, mesh=mesh, in_specs=(P(), espec, espec),
-             out_specs=P(), check_rep=False)
+    @partial(jax.shard_map, mesh=mesh, in_specs=(P(), espec, espec),
+             out_specs=P(), check_vma=False)
     def step(labels, s, r):
         big = jnp.asarray(jnp.iinfo(labels.dtype).max, labels.dtype)
         prop = _local_proposals(labels, s, r, big)
@@ -885,8 +884,8 @@ def make_sharded_step(mesh: Mesh, edge_axes: Sequence[str], label_axis: str,
     lspec = P(label_axis)
     nshards = mesh.shape[label_axis]
 
-    @partial(shard_map, mesh=mesh, in_specs=(lspec, espec, espec),
-             out_specs=lspec, check_rep=False)
+    @partial(jax.shard_map, mesh=mesh, in_specs=(lspec, espec, espec),
+             out_specs=lspec, check_vma=False)
     def step(labels_shard, s, r):
         dtype = labels_shard.dtype
         big = jnp.asarray(jnp.iinfo(dtype).max, dtype)
@@ -946,8 +945,8 @@ def make_sharded_step_fused(mesh: Mesh, edge_axes: Sequence[str],
     lspec = P(label_axis)
     nshards = mesh.shape[label_axis]
 
-    @partial(shard_map, mesh=mesh, in_specs=(lspec, espec, espec),
-             out_specs=lspec, check_rep=False)
+    @partial(jax.shard_map, mesh=mesh, in_specs=(lspec, espec, espec),
+             out_specs=lspec, check_vma=False)
     def step(labels_shard, s, r):
         dtype = labels_shard.dtype
         big = jnp.asarray(jnp.iinfo(dtype).max, dtype)
@@ -999,8 +998,8 @@ def make_streaming_ingest(mesh: Mesh, axes: Sequence[str], *, rounds: int = 4,
             return step(labels, bu, bv)
         labels = jax.lax.fori_loop(0, rounds, body, labels)
 
-        @partial(shard_map, mesh=mesh, in_specs=(P(), qspec, qspec),
-                 out_specs=qspec, check_rep=False)
+        @partial(jax.shard_map, mesh=mesh, in_specs=(P(), qspec, qspec),
+                 out_specs=qspec, check_vma=False)
         def answer(labels, qa, qb):
             return labels[qa] == labels[qb]
 

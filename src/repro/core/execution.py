@@ -361,8 +361,7 @@ def _balanced_factors(ndev: int, naxes: int) -> tuple:
 def make_axis_mesh(axis_names: Sequence[str],
                    devices: Optional[Sequence] = None) -> Mesh:
     """Build a mesh over ``axis_names`` from the available devices, with the
-    device count factored as evenly as possible across the axes. Works on
-    every jax version we support (no AxisType dependency)."""
+    device count factored as evenly as possible across the axes."""
     axis_names = tuple(axis_names)
     devices = list(jax.devices()) if devices is None else list(devices)
     sizes = _balanced_factors(len(devices), len(axis_names))

@@ -7,6 +7,7 @@ from .containers import (  # noqa: F401
     components_oracle,
     compress_edges,
     compress_graph,
+    edges_oracle,
     graph_spec,
     open_edge_file,
     sort_dedup_edges,

@@ -5,8 +5,10 @@ compression, the fused uf_sync hook+compress round, edge relabel/rewrite)
 has two interchangeable implementations — a pure-jnp reference and a Pallas
 TPU kernel — with *identical semantics*, selected by a **kernel policy**:
 
-    auto        pallas on TPU backends, ref elsewhere (the default)
-    pallas      force the compiled Pallas path (TPU)
+    auto        ref on every backend, TPU included (the default; see
+                ``_backend_policy``)
+    pallas      force the compiled Pallas path (TPU); Mosaic refuses
+                every kernel today, so this fails at lowering
     interpret   run the Pallas kernels under ``interpret=True`` — the
                 compiled code path, executable on CPU (CI parity runs)
     ref         force the pure-jnp reference path
@@ -83,10 +85,6 @@ from .scatter_min.kernel import scatter_min as _scatter_min_pallas  # noqa: E402
 from .scatter_min.ref import scatter_min_ref  # noqa: E402
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
 def default_policy() -> str:
     """The process-level policy: ``REPRO_KERNELS`` if set, else ``auto``."""
     env = os.environ.get(ENV_VAR, "").strip().lower()
@@ -99,8 +97,19 @@ def default_policy() -> str:
 
 
 def _backend_policy() -> str:
-    """The backend-detected implementation ``auto`` resolves to."""
-    return "pallas" if _on_tpu() else "ref"
+    """The implementation ``auto`` resolves to: ``ref`` on every backend.
+
+    On a TPU too. The Pallas kernels gather and scatter by data-dependent
+    indices inside the kernel and hold the whole label array as one VMEM
+    block, and Mosaic lowers none of the five (scatter-min, ``dynamic_slice``
+    of a value, and non-2-D gathers are unimplemented). XLA's gather and
+    scatter do compile for the chip at n=2^22, m=2^26. The choice is static,
+    with no fallback at lowering time: an explicit ``pallas`` policy still
+    dispatches the kernels and fails with the lowering error.
+    ``tests/test_tpu_compile.py`` compiles every primitive as resolved here
+    for a described v5e, and the tuner offers ``pallas`` only where this
+    returns it."""
+    return "ref"
 
 
 def resolve_policy(policy: Optional[str] = None) -> str:

@@ -33,6 +33,7 @@ from ..core import streaming
 from ..core.finish import resolve_finish
 from ..legacy.data import EdgeStream
 from ..graphs import generators as gen
+from .compile_cache import enable_compile_cache
 
 
 def run_ingest(n: int, edges: int, batch: int, finish: str = "uf_sync_full",
@@ -123,6 +124,7 @@ def main(argv=None):
                     help="VariantSpec for --chunked")
     ap.add_argument("--survivor-cap", type=int, default=None)
     args = ap.parse_args(argv)
+    enable_compile_cache()
     if args.chunked:
         if args.graph == "ba":
             ap.error("--chunked supports rmat | powerlaw")

@@ -15,19 +15,12 @@ clusters (not exercisable on one CPU host):
 from __future__ import annotations
 
 import jax
-import numpy as np
 
 
 def make_mesh_compat(shape, axes):
-    """``jax.make_mesh`` across jax versions: ``axis_types`` (and on older
-    releases ``jax.sharding.AxisType`` itself) does not exist everywhere, so
-    fall back to a plain device-array ``Mesh`` when it is missing."""
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    ndev = int(np.prod(shape))
-    devices = np.asarray(jax.devices()[:ndev]).reshape(shape)
-    return jax.sharding.Mesh(devices, axes)
+    """``jax.make_mesh`` with every axis in ``Auto`` sharding mode."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

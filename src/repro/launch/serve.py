@@ -24,6 +24,8 @@ from __future__ import annotations
 import argparse
 import sys
 
+from .compile_cache import enable_compile_cache
+
 
 def serve(n: int = 1 << 16, *, batches: int = 32, batch_edges: int = 4096,
           queries: int = 1024, clients: int = 8,
@@ -82,6 +84,7 @@ def main(argv=None):
     ap.add_argument("--flush-ms", type=float, default=1.0,
                     help="max-latency coalescing flush timer")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     serve(args.n, batches=args.batches, batch_edges=args.batch_edges,
           queries=args.queries, clients=args.clients, variant=args.variant,
           exec=args.exec_spec, kernels=args.kernels, seed=args.seed,

@@ -26,6 +26,7 @@ from ..tune.harness import PRIMITIVES
 from ..tune.space import TuneSpec
 from ..tune.tuner import resolve_block_m, resolve_variant, tune_block_m, \
     tune_families
+from .compile_cache import enable_compile_cache
 
 
 def family_proxies(scale: int = 1, *, smoke: bool = False) -> dict:
@@ -108,6 +109,7 @@ def main(argv=None) -> int:
                     help="CI gate: tiny proxies, then assert the cache "
                          "round-trips through a fresh read")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     spec = TuneSpec(grid=args.grid, trials=args.trials, warmup=args.warmup)
     path = cache_path(args.cache)
     cache = SelectionCache(path)

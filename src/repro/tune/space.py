@@ -10,10 +10,13 @@ frozen dataclass with validation on construction and exact
 ``grid`` picks how much of the candidate space the tuner sweeps:
 
 * ``fast`` (default) — the paper's §5-guidance shortlist of variants (one
-  per recommended regime), the backend's compiled policy plus ``ref``, and
-  a three-point pow2 block ladder around the shipped defaults;
-* ``full`` — the entire ``enumerate_variants()`` grid (148 variants), every
-  available kernel policy, and the full pow2 block ladders.
+  per recommended regime) and a three-point pow2 block ladder around the
+  shipped defaults;
+* ``full`` — the entire ``enumerate_variants()`` grid (148 variants) and
+  the full pow2 block ladders.
+
+Block sizes are swept on the Pallas path ``tuner.compiled_policy`` names;
+variants run under the session's kernel policy.
 
 ``trials`` / ``warmup`` parameterize the measurement harness
 (median-of-``trials`` after ``warmup`` discarded runs — see
@@ -84,18 +87,6 @@ class TuneSpec:
             return FAST_VARIANTS
         from ..api import enumerate_variants  # lazy: api imports the kernels
         return tuple(str(v) for v in enumerate_variants())
-
-    def policy_candidates(self) -> tuple:
-        """Kernel policies worth measuring on this backend: the reference
-        path plus every compiled path that can execute here (``pallas`` only
-        on TPU; ``interpret`` everywhere — slow but semantically the
-        compiled code path)."""
-        import jax  # lazy: keep spec construction import-light
-        on_tpu = jax.default_backend() == "tpu"
-        if self.grid == "fast":
-            return ("ref", "pallas") if on_tpu else ("ref", "interpret")
-        return ("ref", "interpret", "pallas") if on_tpu else \
-            ("ref", "interpret")
 
     def block_m_candidates(self) -> tuple:
         """Pow2 edge-block sizes for the 1-D streaming kernels."""

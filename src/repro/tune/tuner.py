@@ -48,9 +48,11 @@ DEFAULT_BLOCK_M = 8192
 
 
 def compiled_policy() -> str:
-    """The compiled kernel path that can execute on this backend (block
-    sizes only matter on the Pallas code path)."""
-    return "pallas" if jax.default_backend() == "tpu" else "interpret"
+    """The Pallas code path to sweep block sizes on (they only matter
+    there): ``pallas`` where ``auto`` dispatches the compiled kernels, else
+    the interpreter, which runs on every backend."""
+    from ..kernels import ops  # lazy: the kernels layer imports this package
+    return "pallas" if ops._backend_policy() == "pallas" else "interpret"
 
 
 def _valid_variant(text) -> Optional[str]:

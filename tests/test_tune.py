@@ -434,6 +434,16 @@ def test_unknown_policy_error_is_distinct(monkeypatch):
         ops.resolve_policy("auto")
 
 
+@pytest.mark.parametrize("auto_impl,swept", [("ref", "interpret"),
+                                              ("pallas", "pallas")])
+def test_block_sweep_policy_follows_auto(monkeypatch, auto_impl, swept):
+    """The tuner sweeps blocks on the compiled kernels only where ``auto``
+    dispatches them, so it never offers a kernel the chip cannot lower."""
+    from repro.tune.tuner import compiled_policy
+    monkeypatch.setattr(ops, "_backend_policy", lambda: auto_impl)
+    assert compiled_policy() == swept
+
+
 def test_embedding_bag_shim_deprecated():
     import jax.numpy as jnp
     table = jnp.ones((8, 4), jnp.float32)
