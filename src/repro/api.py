@@ -889,12 +889,16 @@ class ConnectIt:
         Dispatches through the planned execution backend; every path fills
         the same ConnectivityStats, available as ``.stats``. ``fused`` (an
         ExecutionSpec knob, overridable per call on the single placement)
-        selects the single-dispatch path with no host compaction.
+        selects the single-dispatch path with no host compaction. The call
+        is the host span ``connectit.connectivity`` (docs/API.md,
+        Observability).
         """
-        spec, sampler, finish = ((self.spec, self._sampler, self._finish)
-                                 if not self._auto else self._resolve_auto(g))
-        labels, stats = self._backend.connectivity(
-            g, sampler, finish, key, variant=str(spec), fused=fused)
+        with jax.profiler.TraceAnnotation("connectit.connectivity"):
+            spec, sampler, finish = (
+                (self.spec, self._sampler, self._finish)
+                if not self._auto else self._resolve_auto(g))
+            labels, stats = self._backend.connectivity(
+                g, sampler, finish, key, variant=str(spec), fused=fused)
         self._stats = stats
         if return_stats:
             return labels, stats

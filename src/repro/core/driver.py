@@ -42,6 +42,7 @@ from .primitives import (
     restore_lmax,
 )
 from .sampling import resolve_sampler
+from .tracing import timed_span
 
 
 @dataclasses.dataclass
@@ -78,26 +79,37 @@ class ConnectivityStats:
     chunks: int = 0            # edge chunks streamed through relabel
     spills: int = 0            # survivor-buffer overflow flushes
     survivor_ratio: float = 0.0  # survivors kept / real edges streamed
+    # host seconds per phase (run_connectivity only; see the docstring)
+    sample_s: float = 0.0      # sampling + L_max, until the edge mask is ready
+    compact_s: float = 0.0     # host compaction: copy, index, pad, upload
+    finish_s: float = 0.0      # finish + canonical labels, until rounds on host
+
+
+def _canonical(P, kernels=None):
+    """The closing compress, L_max restored, min-vertex-id labels."""
+    with jax.named_scope("canon"):
+        P = full_compress(P, kernels=kernels)
+        return min_vertex_labels(restore_lmax(P), kernels=kernels)
 
 
 @partial(jax.jit, static_argnames=("finish_fn", "kernels"))
 def _finish_phase(P, senders, receivers, finish_fn, kernels=None):
-    P, rounds = finish_fn(P, senders, receivers)
-    P = full_compress(P, kernels=kernels)
-    P = min_vertex_labels(restore_lmax(P), kernels=kernels)
-    return P, rounds
+    with jax.named_scope("finish"):
+        P, rounds = finish_fn(P, senders, receivers)
+    return _canonical(P, kernels), rounds
 
 
 @jax.jit
 def _prep_sampled(P, senders, receivers):
-    n = P.shape[0] - 1
-    P = full_compress(P)
-    lmax, cnt = most_frequent(P)
-    # drop L_max-internal edges AND the dump-slot padding (senders == n) so
-    # the compacted list — and edges_finish — counts real edges only
-    keep = ~((P[senders] == lmax) & (P[receivers] == lmax)) & (senders < n)
-    P = relabel_lmax(P, lmax)
-    return P, keep, lmax, cnt
+    with jax.named_scope("lmax"):
+        n = P.shape[0] - 1
+        P = full_compress(P)
+        lmax, cnt = most_frequent(P)
+        # drop L_max-internal edges AND the dump-slot padding (senders == n)
+        # so the compacted list — and edges_finish — counts real edges only
+        keep = ~((P[senders] == lmax) & (P[receivers] == lmax)) & (senders < n)
+        P = relabel_lmax(P, lmax)
+        return P, keep, lmax, cnt
 
 
 def bucket_size(k: int, *, pad: str = "pow2", pad_multiple: int = 8,
@@ -152,6 +164,10 @@ def run_connectivity(
     shapes across graphs, a few more dump-slot scatters). ``kernels`` is the
     KernelPolicy for the driver's own finish-phase dispatches (compression +
     canonicalization; the finish callable carries its policy internally).
+
+    Each phase is a host span (``connectit.sample``, ``connectit.compact``,
+    ``connectit.finish``) whose seconds land in ``stats.<phase>_s``; every
+    boundary is a wait the phases need anyway.
     """
     key = jax.random.PRNGKey(0) if key is None else key
     stats = ConnectivityStats(variant=variant, edges_total=g.m)
@@ -161,15 +177,19 @@ def run_connectivity(
         stats.edges_finish = g.m
         stats.edges_finish_padded = g.m_pad
     else:
-        P = sampler_fn(g, key)
-        P, keep, lmax, cnt = _prep_sampled(P, g.senders, g.receivers)
-        senders, receivers, kept = _compact(g.senders, g.receivers, keep, g.n,
-                                            compact_pad, pad)
-        stats.lmax_count = int(cnt)
+        with timed_span("connectit.sample", stats, "sample_s"):
+            P = sampler_fn(g, key)
+            P, keep, lmax, cnt = _prep_sampled(P, g.senders, g.receivers)
+            keep.block_until_ready()
+        with timed_span("connectit.compact", stats, "compact_s"):
+            senders, receivers, kept = _compact(g.senders, g.receivers, keep,
+                                                g.n, compact_pad, pad)
+            stats.lmax_count = int(cnt)
         stats.edges_finish = kept
         stats.edges_finish_padded = int(senders.shape[0])
-    P, rounds = _finish_phase(P, senders, receivers, finish_fn, kernels)
-    stats.finish_rounds = int(rounds)
+    with timed_span("connectit.finish", stats, "finish_s"):
+        P, rounds = _finish_phase(P, senders, receivers, finish_fn, kernels)
+        stats.finish_rounds = int(rounds)
     stats.edges_per_device = (stats.edges_finish,)
     stats.dispatch_sizes = (stats.edges_finish_padded,)
     return P[: g.n], stats
@@ -179,15 +199,15 @@ def run_connectivity(
 def _fused_phase(P, senders, receivers, finish_fn, sampled: bool,
                  kernels=None):
     if sampled:
-        P = full_compress(P, kernels=kernels)
-        lmax, cnt = most_frequent(P)
-        P = relabel_lmax(P, lmax)
+        with jax.named_scope("lmax"):
+            P = full_compress(P, kernels=kernels)
+            lmax, cnt = most_frequent(P)
+            P = relabel_lmax(P, lmax)
     else:
         cnt = jnp.int32(0)
-    P, rounds = finish_fn(P, senders, receivers)
-    P = full_compress(P, kernels=kernels)
-    P = min_vertex_labels(restore_lmax(P), kernels=kernels)
-    return P, rounds, cnt
+    with jax.named_scope("finish"):
+        P, rounds = finish_fn(P, senders, receivers)
+    return _canonical(P, kernels), rounds, cnt
 
 
 def run_connectivity_fused(
@@ -233,16 +253,18 @@ def run_spanning_forest(
     key = jax.random.PRNGKey(0) if key is None else key
     if sampler_fn is None:
         P = init_labels(g.n)
-        st, _ = uf_sync_forest(P, g.senders, g.receivers, compress=compress,
-                               kernels=kernels)
+        with jax.named_scope("finish"):
+            st, _ = uf_sync_forest(P, g.senders, g.receivers,
+                                   compress=compress, kernels=kernels)
     else:
         st0 = sampler_fn(g, key, want_forest=True)
         P, keep, lmax, cnt = _prep_sampled(st0.P, g.senders, g.receivers)
         senders, receivers, _ = _compact(g.senders, g.receivers, keep, g.n,
                                          compact_pad, pad)
-        st, _ = uf_sync_forest(P, senders, receivers,
-                               fu=st0.fu, fv=st0.fv, compress=compress,
-                               kernels=kernels)
+        with jax.named_scope("finish"):
+            st, _ = uf_sync_forest(P, senders, receivers,
+                                   fu=st0.fu, fv=st0.fv, compress=compress,
+                                   kernels=kernels)
     fu = np.asarray(st.fu)
     fv = np.asarray(st.fv)
     sel = (fu >= 0) & (fv >= 0)

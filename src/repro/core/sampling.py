@@ -27,6 +27,7 @@ deprecation shim: ``get_sampler``.
 
 from __future__ import annotations
 
+import functools
 import warnings
 from typing import Callable
 
@@ -44,10 +45,14 @@ SamplerFn = Callable[..., object]  # (g, key, *, want_forest=False)
 def _jit_sampler(fn: SamplerFn) -> SamplerFn:
     # jit at instantiation (memoized ⇒ stable identity ⇒ stable compile
     # cache): every sampler is trace-safe, and eager lax.while_loop closures
-    # would otherwise re-lower on each call
-    jitted = jax.jit(fn, static_argnames=("want_forest",))
-    jitted.__name__ = fn.__name__
-    return jitted
+    # would otherwise re-lower on each call. The whole body, edge selection
+    # and union-find alike, runs under the device scope "sample".
+    @functools.wraps(fn)
+    def sample(g: Graph, key: jax.Array, *, want_forest: bool = False):
+        with jax.named_scope("sample"):
+            return fn(g, key, want_forest=want_forest)
+
+    return jax.jit(sample, static_argnames=("want_forest",))
 
 
 _REGISTRY = FactoryRegistry("sampling scheme", wrap=_jit_sampler)

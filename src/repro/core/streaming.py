@@ -57,7 +57,8 @@ def insert_batch_fn(state: StreamState, batch_u, batch_v,
     u = jnp.concatenate([batch_u, batch_v])
     v = jnp.concatenate([batch_v, batch_u])
     u, v = rewrite_edges(state.P, u, v, kernels=kernels)
-    P, _ = finish_fn(state.P, u, v)
+    with jax.named_scope("finish"):
+        P, _ = finish_fn(state.P, u, v)
     return StreamState(full_compress(P, kernels=kernels))
 
 
@@ -87,7 +88,8 @@ def insert_batch_rounds_fn(state: StreamState, batch_u, batch_v,
     u = jnp.concatenate([batch_u, batch_v])
     v = jnp.concatenate([batch_v, batch_u])
     u, v = rewrite_edges(state.P, u, v, kernels=kernels)
-    P, rounds = finish_fn(state.P, u, v)
+    with jax.named_scope("finish"):
+        P, rounds = finish_fn(state.P, u, v)
     return StreamState(full_compress(P, kernels=kernels)), rounds
 
 
@@ -117,8 +119,10 @@ def process_batch_rounds_fn(state: StreamState, batch_u, batch_v, qa, qb,
 
 def snapshot_query(P: jax.Array, qa, qb) -> jax.Array:
     """IsConnected against a raw compressed label buffer (single-device
-    snapshot read; mesh placements have their own shard_map query)."""
-    return P[qa] == P[qb]
+    snapshot read; mesh placements have their own shard_map query), under
+    the device scope ``query``."""
+    with jax.named_scope("query"):
+        return P[qa] == P[qb]
 
 
 _snapshot_query_jit = jax.jit(snapshot_query)
@@ -132,15 +136,16 @@ def make_snapshot_commit(finish_fn: Callable, *,
 
     ``committed`` is read, never written; ``shadow`` is dead state whose
     buffer is donated to the output when ``donate`` is set (double-buffer
-    rotation — see the section comment above). Mesh placements build the
-    equivalent program from their stream insert programs
-    (``core.execution``)."""
+    rotation — see the section comment above). The program runs under the
+    device scope ``commit``. Mesh placements build the equivalent program
+    from their stream insert programs (``core.execution``)."""
 
     def commit(committed, shadow, u, v):
         del shadow  # donated: its device buffer backs the new epoch
-        state, rounds = insert_batch_rounds_fn(
-            StreamState(committed), u, v, finish_fn, kernels)
-        return state.P, rounds
+        with jax.named_scope("commit"):
+            state, rounds = insert_batch_rounds_fn(
+                StreamState(committed), u, v, finish_fn, kernels)
+            return state.P, rounds
 
     return jax.jit(commit, donate_argnums=(1,) if donate else ())
 

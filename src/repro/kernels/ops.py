@@ -41,6 +41,7 @@ This layer owns the dispatch contract between core arrays and kernels:
 
 from __future__ import annotations
 
+import functools
 import os
 import warnings
 from typing import Optional
@@ -200,6 +201,20 @@ def _pad_edges(arrs, fills, block_m: int):
 # row ``n`` — applies the dispatch contract, and returns core-shaped results.
 # ---------------------------------------------------------------------------
 
+def _scoped(op):
+    """Run ``op`` under the device scope of its own name, so that whatever
+    implements it (``ref`` or a Pallas kernel) carries that name in the
+    compiled program's op metadata and in a profiler trace."""
+
+    @functools.wraps(op)
+    def run(*args, **kwargs):
+        with jax.named_scope(op.__name__):
+            return op(*args, **kwargs)
+
+    return run
+
+
+@_scoped
 def scatter_min(P: jax.Array, idx: jax.Array, vals: jax.Array,
                 mask: Optional[jax.Array] = None, *,
                 policy: Optional[str] = None,
@@ -229,6 +244,7 @@ def scatter_min(P: jax.Array, idx: jax.Array, vals: jax.Array,
     return out[: n + 1]
 
 
+@_scoped
 def pointer_jump(labels: jax.Array, *, k: int = 1,
                  policy: Optional[str] = None, block: Optional[int] = None
                  ) -> jax.Array:
@@ -249,6 +265,7 @@ def pointer_jump(labels: jax.Array, *, k: int = 1,
     return out[:L]
 
 
+@_scoped
 def hook_compress(P: jax.Array, senders: jax.Array, receivers: jax.Array,
                   *, k: int = 1, mask: Optional[jax.Array] = None,
                   policy: Optional[str] = None,
@@ -278,6 +295,7 @@ def hook_compress(P: jax.Array, senders: jax.Array, receivers: jax.Array,
     return out[: n + 1]
 
 
+@_scoped
 def compact_mask(mask: jax.Array, vals: jax.Array, cap: int, *,
                  policy: Optional[str] = None) -> tuple:
     """Stream-compact the ``True`` positions of ``mask`` (and their ``vals``)
@@ -303,6 +321,7 @@ def compact_mask(mask: jax.Array, vals: jax.Array, cap: int, *,
     return idx, out
 
 
+@_scoped
 def edge_relabel(labels: jax.Array, senders: jax.Array, receivers: jax.Array,
                  *, policy: Optional[str] = None,
                  block_m: Optional[int] = None) -> jax.Array:
@@ -323,6 +342,7 @@ def edge_relabel(labels: jax.Array, senders: jax.Array, receivers: jax.Array,
     return out[:L]
 
 
+@_scoped
 def edge_rewrite(labels: jax.Array, senders: jax.Array, receivers: jax.Array,
                  *, policy: Optional[str] = None,
                  block_m: Optional[int] = None):

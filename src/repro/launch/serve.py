@@ -63,6 +63,14 @@ def serve(n: int = 1 << 16, *, batches: int = 32, batch_edges: int = 4096,
               f"{server.num_components()} (commit shapes compiled: "
               f"{list(st.commit_shapes)}, query shapes: "
               f"{list(st.query_shapes)})")
+        ms = lambda s, k: 1e3 * s / max(k, 1)  # noqa: E731
+        print(f"[serve] host ms, mean: insert wait "
+              f"{ms(st.insert_wait_s, res.inserts):.2f}, query wait "
+              f"{ms(st.query_wait_s, res.queries):.2f} (admission to batch "
+              f"cut); commit {ms(st.commit_s, st.commit_batches):.2f} a "
+              f"batch (cut to acknowledgement); answer "
+              f"{ms(st.answer_s, st.query_batches):.2f} a batch (cut to "
+              f"answers on the host)")
     return res.achieved_qps * queries, server
 
 

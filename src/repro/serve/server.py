@@ -24,6 +24,10 @@ new epoch's labels are materialized before rotating buffers — so "epoch e
 committed" means the device state is real, and insert latency measured by
 the load generator includes device time. Queries overlap freely with the
 in-flight commit; they read the prior epoch by construction.
+
+Each loop names its phases as host spans (``connectit.serve.coalesce``,
+``connectit.serve.commit``, ``connectit.serve.answer``) and sums their
+seconds into ``ServerStats`` (docs/API.md, Observability).
 """
 
 from __future__ import annotations
@@ -36,6 +40,7 @@ from typing import Optional
 import jax
 import numpy as np
 
+from ..core.tracing import timed_span
 from .config import ServeConfig
 from .snapshot import SnapshotStore
 from .tenancy import DEFAULT_TENANT, TenantRegistry
@@ -57,7 +62,19 @@ class TenantStats:
 
 @dataclasses.dataclass
 class ServerStats:
-    """A point-in-time snapshot of the server's counters."""
+    """A point-in-time snapshot of the server's counters.
+
+    The four host-second counters are cumulative. ``insert_wait_s`` and
+    ``query_wait_s`` sum, over requests, the time from admission (the
+    request queued) to the cut of the batch that took it. ``commit_s`` sums,
+    over insert batches, the span ``connectit.serve.commit``: batch cut to
+    the batch's last acknowledgement, device commit and buffer rotation
+    included. ``answer_s`` sums, over query batches, the span
+    ``connectit.serve.answer``: batch cut to the answers on the host and
+    handed to every request of the batch. Divide the waits by the requests
+    sent and the other two by ``commit_batches`` / ``query_batches`` for
+    means (``repro.launch.serve`` prints them).
+    """
 
     exec: str
     variant: str
@@ -73,6 +90,10 @@ class ServerStats:
     commit_shapes: tuple
     query_shapes: tuple
     tenants: dict
+    insert_wait_s: float
+    query_wait_s: float
+    commit_s: float
+    answer_s: float
 
 
 class _Pending:
@@ -123,6 +144,11 @@ class Server:
         self._queries_answered = 0
         self._commit_shapes: set = set()
         self._query_shapes: set = set()
+        # host seconds (ServerStats); the spans add the last two
+        self._insert_wait_s = 0.0
+        self._query_wait_s = 0.0
+        self._commit_s = 0.0
+        self._answer_s = 0.0
 
     # -- lifecycle -----------------------------------------------------------
 
@@ -312,23 +338,32 @@ class Server:
 
     async def _coalesce(self, queue: deque, cap: int, arrival: asyncio.Event,
                         full: asyncio.Event) -> list:
-        """Wait for traffic, then up to the flush window for a full batch."""
+        """Wait for traffic, then up to the flush window for a full batch.
+        From the wake-up on arrival to the cut is the host span
+        ``connectit.serve.coalesce``."""
         await arrival.wait()
-        if not queue:          # raced a flush with an empty queue
-            arrival.clear()
-            return []
-        flush_s = self.config.flush_s
-        if flush_s > 0 and not full.is_set():
-            # the oldest request bounds the extra wait: never more than
-            # flush_ms past its admission, and none if the loop was busy
-            loop = asyncio.get_running_loop()
-            timeout = queue[0].t + flush_s - loop.time()
-            if timeout > 0:
-                try:
-                    await asyncio.wait_for(full.wait(), timeout)
-                except asyncio.TimeoutError:
-                    pass
-        return self._take(queue, cap, arrival, full)
+        with jax.profiler.TraceAnnotation("connectit.serve.coalesce"):
+            if not queue:          # raced a flush with an empty queue
+                arrival.clear()
+                return []
+            flush_s = self.config.flush_s
+            if flush_s > 0 and not full.is_set():
+                # the oldest request bounds the extra wait: never more than
+                # flush_ms past its admission, and none if the loop was busy
+                loop = asyncio.get_running_loop()
+                timeout = queue[0].t + flush_s - loop.time()
+                if timeout > 0:
+                    try:
+                        await asyncio.wait_for(full.wait(), timeout)
+                    except asyncio.TimeoutError:
+                        pass
+            return self._take(queue, cap, arrival, full)
+
+    @staticmethod
+    def _waited(batch: list) -> float:
+        """Seconds from admission to now (the cut), summed over requests."""
+        now = asyncio.get_running_loop().time()
+        return sum(now - p.t for p in batch)
 
     async def _insert_loop(self):
         cfg = self.config
@@ -338,40 +373,48 @@ class Server:
                                          self._insert_full)
             if not batch:
                 continue
-            total = sum(p.k for p in batch)
-            self._pending_edges -= total
-            ins = [p for p in batch if p.kind == "ins"]
-            dels = [p for p in batch if p.kind == "del"]
-            empty = np.empty((0,), np.int32)
-            u = np.concatenate([p.u for p in ins]) if ins else empty
-            v = np.concatenate([p.v for p in ins]) if ins else empty
-            try:
-                if dels:
-                    du = np.concatenate([p.u for p in dels])
-                    dv = np.concatenate([p.v for p in dels])
-                    pending = self.store.begin_commit(u, v, du, dv)
-                else:
-                    pending = self.store.begin_commit(u, v)
-                await asyncio.to_thread(jax.block_until_ready,
-                                        pending.labels)
-                epoch = self.store.finish_commit(pending)
-            except Exception as e:  # noqa: BLE001 - fanned out to callers
-                for p in batch:
-                    if not p.future.done():
-                        p.future.set_exception(e)
-                continue
-            self._commit_batches += 1
-            self._commit_shapes.add(int(self.store._ops.batch_size(
-                sum(p.k for p in ins))))
-            for p in batch:
-                if p.kind == "del":
-                    self._tstats[p.tenant].deletes_committed += p.k
-                else:
-                    self._tstats[p.tenant].edges_committed += p.k
-                if not p.future.done():
-                    p.future.set_result(epoch)
+            self._insert_wait_s += self._waited(batch)
+            with timed_span("connectit.serve.commit", self, "_commit_s"):
+                if not await self._commit(batch):
+                    continue
             async with self._space:
                 self._space.notify_all()
+
+    async def _commit(self, batch: list) -> bool:
+        """Commit one cut batch and acknowledge every request in it;
+        False (each request failed with the error) if the commit raised."""
+        total = sum(p.k for p in batch)
+        self._pending_edges -= total
+        ins = [p for p in batch if p.kind == "ins"]
+        dels = [p for p in batch if p.kind == "del"]
+        empty = np.empty((0,), np.int32)
+        u = np.concatenate([p.u for p in ins]) if ins else empty
+        v = np.concatenate([p.v for p in ins]) if ins else empty
+        try:
+            if dels:
+                du = np.concatenate([p.u for p in dels])
+                dv = np.concatenate([p.v for p in dels])
+                pending = self.store.begin_commit(u, v, du, dv)
+            else:
+                pending = self.store.begin_commit(u, v)
+            await asyncio.to_thread(jax.block_until_ready, pending.labels)
+            epoch = self.store.finish_commit(pending)
+        except Exception as e:  # noqa: BLE001 - fanned out to callers
+            for p in batch:
+                if not p.future.done():
+                    p.future.set_exception(e)
+            return False
+        self._commit_batches += 1
+        self._commit_shapes.add(int(self.store._ops.batch_size(
+            sum(p.k for p in ins))))
+        for p in batch:
+            if p.kind == "del":
+                self._tstats[p.tenant].deletes_committed += p.k
+            else:
+                self._tstats[p.tenant].edges_committed += p.k
+            if not p.future.done():
+                p.future.set_result(epoch)
+        return True
 
     async def _query_loop(self):
         cfg = self.config
@@ -382,29 +425,35 @@ class Server:
                                          self._query_full)
             if not batch:
                 continue
-            qa = np.concatenate([p.u for p in batch])
-            qb = np.concatenate([p.v for p in batch])
-            try:
-                ans, epoch = self.store.query(qa, qb)
-                ans = await asyncio.to_thread(np.asarray, ans)
-            except Exception as e:  # noqa: BLE001 - fanned out to callers
-                for p in batch:
-                    if not p.future.done():
-                        p.future.set_exception(e)
-                continue
-            self._query_batches += 1
-            self._query_shapes.add(int(self.store._ops.batch_size(
-                int(qa.shape[0]))))
-            off = 0
+            self._query_wait_s += self._waited(batch)
+            with timed_span("connectit.serve.answer", self, "_answer_s"):
+                await self._answer(batch)
+
+    async def _answer(self, batch: list) -> None:
+        """Answer one cut query batch against the committed snapshot."""
+        qa = np.concatenate([p.u for p in batch])
+        qb = np.concatenate([p.v for p in batch])
+        try:
+            ans, epoch = self.store.query(qa, qb)
+            ans = await asyncio.to_thread(np.asarray, ans)
+        except Exception as e:  # noqa: BLE001 - fanned out to callers
             for p in batch:
-                part = ans[off: off + p.k]
-                off += p.k
-                st = self._tstats[p.tenant]
-                st.queries += p.k
-                st.positives += int(part.sum())
-                self._queries_answered += p.k
                 if not p.future.done():
-                    p.future.set_result((part, epoch))
+                    p.future.set_exception(e)
+            return
+        self._query_batches += 1
+        self._query_shapes.add(int(self.store._ops.batch_size(
+            int(qa.shape[0]))))
+        off = 0
+        for p in batch:
+            part = ans[off: off + p.k]
+            off += p.k
+            st = self._tstats[p.tenant]
+            st.queries += p.k
+            st.positives += int(part.sum())
+            self._queries_answered += p.k
+            if not p.future.done():
+                p.future.set_result((part, epoch))
 
     # -- sync conveniences (no event loop required) --------------------------
 
@@ -485,4 +534,7 @@ class Server:
             commit_shapes=tuple(sorted(self._commit_shapes)),
             query_shapes=tuple(sorted(self._query_shapes)),
             tenants={k: dataclasses.replace(v)
-                     for k, v in self._tstats.items()})
+                     for k, v in self._tstats.items()},
+            insert_wait_s=self._insert_wait_s,
+            query_wait_s=self._query_wait_s,
+            commit_s=self._commit_s, answer_s=self._answer_s)
