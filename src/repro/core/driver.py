@@ -8,7 +8,9 @@ orchestrator behind the ``repro.api.ConnectIt`` session object:
      label -1 (Theorem 4's "smallest possible ID" relabeling)
   3. *compact* the finish-phase edge list: edges internal to L_max are
      dropped on the host (this is where the paper's m - X + Y edge saving
-     is realized — masked edges would still cost memory bandwidth)
+     is realized — masked edges would still cost memory bandwidth). The
+     device marks the CSR rows of the senders outside L_max, and the host
+     adds back the reverse of each kept edge into L_max
   4. run the finish phase (jit) on the compacted edges
   5. compress + restore -1 → canonical min-vertex-id labels
 
@@ -99,15 +101,32 @@ def _finish_phase(P, senders, receivers, finish_fn, kernels=None):
     return _canonical(P, kernels), rounds
 
 
+def _rows_of(flag, indptr, m_pad: int):
+    """Spread a per-vertex flag over the vertex's CSR row: slot ``i`` reads
+    its sender's flag, padding reads False. One scatter of the n + 1
+    row-start differences (empty rows share a start; their differences add
+    up) and one prefix sum, in place of a gather at every slot."""
+    with jax.named_scope("rows"):
+        n = flag.shape[0] - 1
+        flag = flag.at[n].set(False).astype(jnp.int32)
+        step = flag - jnp.concatenate([jnp.zeros((1,), jnp.int32), flag[:-1]])
+        starts = jnp.zeros((m_pad + 1,), jnp.int32).at[indptr[: n + 1]].add(
+            step)
+        return jnp.cumsum(starts[:m_pad]) != 0
+
+
 @jax.jit
-def _prep_sampled(P, senders, receivers):
+def _prep_sampled(P, g: Graph):
+    """Compress the sample, find L_max, pin it to -1, and mark the slots
+    whose edges the finish may not skip: the rows of the senders outside
+    L_max. An edge from L_max out of it is left to its reverse, which
+    ``_finish_edges`` adds back (the graph stores both directions). Padding
+    is never marked, so the compacted list and ``edges_finish`` count real
+    edges only."""
     with jax.named_scope("lmax"):
-        n = P.shape[0] - 1
         P = full_compress(P)
         lmax, cnt = most_frequent(P)
-        # drop L_max-internal edges AND the dump-slot padding (senders == n)
-        # so the compacted list — and edges_finish — counts real edges only
-        keep = ~((P[senders] == lmax) & (P[receivers] == lmax)) & (senders < n)
+        keep = _rows_of(P != lmax, g.indptr, g.m_pad)
         P = relabel_lmax(P, lmax)
         return P, keep, lmax, cnt
 
@@ -131,18 +150,34 @@ def bucket_size(k: int, *, pad: str = "pow2", pad_multiple: int = 8,
     return round_up(size, shards)
 
 
-def _compact(senders, receivers, keep, n_dump: int, pad_multiple: int = 8,
-             pad: str = "multiple"):
-    keep_np = np.asarray(keep)
-    s = np.asarray(senders)[keep_np]
-    r = np.asarray(receivers)[keep_np]
+def _finish_edges(g: Graph, keep):
+    """The finish's edge list on the host, from ``_prep_sampled``'s mask: the
+    marked slots and the reverse of each marked edge whose receiver has no
+    marked row (it lies in L_max), so that both directions of every edge
+    leaving L_max reach the finish."""
+    keep = np.asarray(keep)
+    s = np.asarray(g.senders)[keep]
+    r = np.asarray(g.receivers)[keep]
+    marked = np.zeros((g.n + 1,), bool)
+    marked[s] = True
+    back = ~marked[r]
+    return np.concatenate([s, r[back]]), np.concatenate([r, s[back]])
+
+
+def _pad_edges(s: np.ndarray, r: np.ndarray, dump: int, size: int):
+    """Host edges into ``size`` device slots, padded with the dump id."""
+    out_s = np.full((size,), dump, np.int32)
+    out_r = np.full((size,), dump, np.int32)
+    out_s[: s.shape[0]] = s
+    out_r[: r.shape[0]] = r
+    return jnp.asarray(out_s), jnp.asarray(out_r)
+
+
+def _compact(g: Graph, keep, pad_multiple: int = 8, pad: str = "multiple"):
+    s, r = _finish_edges(g, keep)
     kept = int(s.shape[0])
-    m_pad = bucket_size(kept, pad=pad, pad_multiple=pad_multiple)
-    s_out = np.full((m_pad,), n_dump, np.int32)
-    r_out = np.full((m_pad,), n_dump, np.int32)
-    s_out[:kept] = s
-    r_out[:kept] = r
-    return jnp.asarray(s_out), jnp.asarray(r_out), kept
+    size = bucket_size(kept, pad=pad, pad_multiple=pad_multiple)
+    return (*_pad_edges(s, r, g.n, size), kept)
 
 
 def run_connectivity(
@@ -179,11 +214,10 @@ def run_connectivity(
     else:
         with timed_span("connectit.sample", stats, "sample_s"):
             P = sampler_fn(g, key)
-            P, keep, lmax, cnt = _prep_sampled(P, g.senders, g.receivers)
+            P, keep, lmax, cnt = _prep_sampled(P, g)
             keep.block_until_ready()
         with timed_span("connectit.compact", stats, "compact_s"):
-            senders, receivers, kept = _compact(g.senders, g.receivers, keep,
-                                                g.n, compact_pad, pad)
+            senders, receivers, kept = _compact(g, keep, compact_pad, pad)
             stats.lmax_count = int(cnt)
         stats.edges_finish = kept
         stats.edges_finish_padded = int(senders.shape[0])
@@ -258,9 +292,8 @@ def run_spanning_forest(
                                    compress=compress, kernels=kernels)
     else:
         st0 = sampler_fn(g, key, want_forest=True)
-        P, keep, lmax, cnt = _prep_sampled(st0.P, g.senders, g.receivers)
-        senders, receivers, _ = _compact(g.senders, g.receivers, keep, g.n,
-                                         compact_pad, pad)
+        P, keep, lmax, cnt = _prep_sampled(st0.P, g)
+        senders, receivers, _ = _compact(g, keep, compact_pad, pad)
         with jax.named_scope("finish"):
             st, _ = uf_sync_forest(P, senders, receivers,
                                    fu=st0.fu, fv=st0.fv, compress=compress,

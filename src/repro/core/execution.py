@@ -392,14 +392,6 @@ def plan_mesh(spec: ExecutionSpec, mesh: Optional[Mesh] = None
 bucket_size = driver.bucket_size  # one pad-policy definition (driver.py)
 
 
-def _pad_edges_np(s: np.ndarray, r: np.ndarray, dump: int, size: int):
-    out_s = np.full((size,), dump, np.int32)
-    out_r = np.full((size,), dump, np.int32)
-    out_s[: s.shape[0]] = s
-    out_r[: r.shape[0]] = r
-    return jnp.asarray(out_s), jnp.asarray(out_r)
-
-
 def _per_chunk_counts(k: int, size: int, shards: int) -> tuple:
     """Real-element count per contiguous shard chunk of a padded dispatch
     whose first ``k`` slots are real (padding is always a suffix)."""
@@ -793,14 +785,12 @@ class _MeshBackend(_Backend):
                 (g.senders, g.receivers), (g.n, g.n), size)
         else:
             P0 = sampler_fn(g, key)
-            P0, keep, _, cnt = driver._prep_sampled(P0, g.senders, g.receivers)
-            keep = np.asarray(keep)
-            s = np.asarray(g.senders)[keep]
-            r = np.asarray(g.receivers)[keep]
+            P0, keep, _, cnt = driver._prep_sampled(P0, g)
+            s, r = driver._finish_edges(g, keep)
             stats.lmax_count = int(cnt)
             kept = int(s.shape[0])
             size = self._bucket(kept)
-            senders, receivers = _pad_edges_np(s, r, g.n, size)
+            senders, receivers = driver._pad_edges(s, r, g.n, size)
         stats.edges_finish = kept
         stats.edges_finish_padded = size
         shards = self.edge_shards

@@ -42,7 +42,14 @@ INT32_MAX = np.iinfo(np.int32).max
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass(frozen=True)
 class Graph:
-    """COO + CSR static graph. All arrays are device arrays."""
+    """COO + CSR static graph. All arrays are device arrays.
+
+    The COO list is sorted by sender and ``indptr`` indexes it: row ``v`` is
+    slots ``indptr[v]:indptr[v + 1]``. Every stored edge ``(u, v)`` is also
+    stored as ``(v, u)``, as often: ``build_graph`` symmetrizes or, with
+    ``symmetrize=False``, checks it, and the benchmark's device build
+    symmetrizes. The sampled driver picks the finish's edges by sender rows
+    alone and relies on it (``core.driver._prep_sampled``)."""
 
     senders: jax.Array      # (m_pad,) int32, sentinel = n for padding
     receivers: jax.Array    # (m_pad,) int32
@@ -126,6 +133,15 @@ def sort_dedup_edges(edges: np.ndarray, n: int, *, symmetrize: bool = True,
     return edges
 
 
+def _mirrored(edges: np.ndarray) -> bool:
+    """Whether (sender, receiver)-sorted ``edges`` hold each ``(u, v)`` as
+    often as ``(v, u)``: the sorted reversed keys equal the forward ones."""
+    key = (edges[:, 0].astype(np.int64) << 32) | edges[:, 1].astype(np.int64)
+    back = (edges[:, 1].astype(np.int64) << 32) | edges[:, 0].astype(np.int64)
+    back.sort()
+    return bool(np.array_equal(key, back))
+
+
 def build_graph(
     edges: np.ndarray,
     n: int,
@@ -134,8 +150,14 @@ def build_graph(
     dedup: bool = True,
     pad_multiple: int = 8,
 ) -> Graph:
-    """Build a Graph from a host-side (k, 2) int array of undirected edges."""
+    """Build a Graph from a host-side (k, 2) int array of undirected edges.
+
+    ``symmetrize=False`` takes input that already holds both directions of
+    every edge, and raises ``ValueError`` on input that does not."""
     edges = sort_dedup_edges(edges, n, symmetrize=symmetrize, dedup=dedup)
+    if not symmetrize and not _mirrored(edges):
+        raise ValueError("symmetrize=False needs every edge (u, v) stored as "
+                         "(v, u) too, as often")
     m = int(edges.shape[0])
     m_pad = max(round_up(m, pad_multiple), pad_multiple)
     senders = _pad_to(edges[:, 0], m_pad, n)

@@ -13,6 +13,8 @@ GRAPHS = {
     "rmat": lambda: gen.rmat(200, 700, seed=2),
     "torus": lambda: gen.torus((4, 4, 4)),
     "star": lambda: gen.star(40),
+    "ba": lambda: gen.barabasi_albert(300, 2, seed=5),
+    "path": lambda: gen.path(200),
 }
 
 

@@ -46,7 +46,7 @@ def programs(graph):
     s, r = graph.senders, graph.receivers
     return {
         "sampler": scopes(sampler.lower(graph, key)),
-        "lmax": scopes(driver._prep_sampled.lower(P, s, r)),
+        "lmax": scopes(driver._prep_sampled.lower(P, graph)),
         "finish": scopes(driver._finish_phase.lower(P, s, r, finish)),
     }
 
@@ -54,7 +54,7 @@ def programs(graph):
 @pytest.mark.parametrize("program, scope", [
     ("sampler", "sample"), ("sampler", "hook_compress"),
     ("sampler", "pointer_jump"),
-    ("lmax", "lmax"), ("lmax", "pointer_jump"),
+    ("lmax", "lmax"), ("lmax", "pointer_jump"), ("lmax", "rows"),
     ("finish", "finish"), ("finish", "canon"), ("finish", "hook_compress"),
     ("finish", "pointer_jump"), ("finish", "scatter_min"),
 ])
@@ -69,6 +69,33 @@ def test_fused_program_carries_scopes(graph):
     got = scopes(driver._fused_phase.lower(P, graph.senders, graph.receivers,
                                            finish, True))
     assert {"lmax", "finish", "canon"} <= got
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs nested in it."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for param in eqn.params.values():
+            for sub in param if isinstance(param, (list, tuple)) else [param]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _eqns(sub)
+
+
+@pytest.mark.parametrize("make_graph", [
+    lambda: gen.rmat(1024, 1 << 13, seed=0), lambda: gen.path(300),
+], ids=["rmat", "path"])
+def test_lmax_gathers_at_edge_slots(make_graph):
+    """L_max's mask reads no label at the edge slots: it spreads a flag per
+    vertex over the CSR rows instead."""
+    g = make_graph()
+    P = make_sampler("kout", k=2, variant="hybrid")(g, jax.random.PRNGKey(0))
+    assert g.m_pad != P.shape[0]
+    jaxpr = jax.make_jaxpr(driver._prep_sampled)(P, g).jaxpr
+    at_slots = [e for e in _eqns(jaxpr) if e.primitive.name == "gather"
+                and e.outvars[0].aval.size == g.m_pad]
+    assert at_slots == []
+    assert "lmax" in scopes(driver._prep_sampled.lower(P, g))
 
 
 def _primitive(name, policy):
